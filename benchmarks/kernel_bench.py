@@ -53,9 +53,9 @@ def main(argv=None) -> int:
     qp = jnp.asarray(rng.normal(size=(bsrv, h, d)), jnp.float32)
     kd = jnp.asarray(rng.normal(size=(bsrv, s, kvh, d)), jnp.float32)
     vd = jnp.asarray(rng.normal(size=(bsrv, s, kvh, d)), jnp.float32)
-    kp = kd.reshape(bsrv * nblk, bs, kvh, d)
+    kp = kd.reshape(bsrv * nblk, bs, kvh, d).swapaxes(1, 2)
     kp = jnp.concatenate([jnp.zeros((1,) + kp.shape[1:], kp.dtype), kp])
-    vp = vd.reshape(bsrv * nblk, bs, kvh, d)
+    vp = vd.reshape(bsrv * nblk, bs, kvh, d).swapaxes(1, 2)
     vp = jnp.concatenate([jnp.zeros((1,) + vp.shape[1:], vp.dtype), vp])
     bt = jnp.arange(1, 1 + bsrv * nblk, dtype=jnp.int32).reshape(bsrv, nblk)
     lens_p = jnp.full((bsrv,), s - 3, jnp.int32)     # ragged tail

@@ -1,2 +1,3 @@
-"""Pallas TPU kernels (interpret-mode validated on CPU; see ops.py)."""
+"""Pallas TPU kernels: compiled by Mosaic on a TPU, interpreted
+elsewhere and checked there against ``ref.py`` (see ops.py)."""
 from repro.kernels import ops, ref

@@ -27,7 +27,7 @@ NEG_INF = -1e30
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, bk: int, scale: float,
                    nk: int):
-    ik = pl.program_id(1)
+    i, ik = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -35,7 +35,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0]
+    length = len_ref[i]
     k_start = ik * bk
 
     @pl.when(k_start < length)
@@ -92,23 +92,25 @@ def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
     lens = jnp.repeat(lengths.astype(jnp.int32), kvh)      # [b*kvh]
 
     kernel = functools.partial(_decode_kernel, bk=bk, scale=scale, nk=nk)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,       # per-row filled lengths
         grid=(b * kvh, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, kk: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, d), lambda i, kk: (i, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, kk: (i, kk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, kk: (i, kk, 0)),
+            pl.BlockSpec((1, g, d), lambda i, kk, ln: (i, 0, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, kk, ln: (i, kk, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, kk, ln: (i, kk, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, d), lambda i, kk: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * kvh, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, g, d), lambda i, kk, ln: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, d), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * kvh, g, d), q.dtype),
         interpret=interpret,
     )(lens, qr, kr, vr)
     return out.reshape(b, kvh, g, d).reshape(b, h, d)

@@ -6,9 +6,10 @@ the online-softmax state (acc, m, l) lives in scratch across kv steps.
 
 TPU adaptation notes (DESIGN.md §2): VMEM working set per grid cell =
 q block [g*bq, d] + k/v blocks [bk, d] + acc [g*bq, d] f32 + score tile
-[g*bq, bk] f32 — ~6.5 MB at the defaults (bq=bk=512, d=128, g=4), well
-under v5e's ~128 MB VMEM, with every matmul dim a multiple of 128 (MXU
-aligned). Causal skipping: kv blocks entirely above the diagonal do no
+[g*bq, bk] f32. ``bq`` shrinks with the group size ``g`` so that
+``g*bq <= block_q`` rows: at the defaults (block_q=bk=512, d=128) the
+cell stays near 2 MB for any ``g``, inside the 16 MB of scoped VMEM a
+v5e kernel gets by default. Causal skipping: kv blocks entirely above the diagonal do no
 work (``pl.when``).
 """
 from __future__ import annotations
@@ -81,7 +82,12 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
     b, s, h, d = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     g = h // kvh
+    # the q block holds g*bq rows (all heads of a KV group); shrink bq
+    # with g so that block, the [g*bq, bk] score tile and the f32
+    # accumulator stay inside the scoped VMEM budget
     bq = min(block_q, s)
+    while bq > 8 and g * bq > block_q:
+        bq //= 2
     while s % bq:
         bq //= 2
     bk = min(block_k, skv)

@@ -8,7 +8,8 @@ fallback into one VMEM pass over [M, D] tiles — one read of x, one
 write of y, instead of the 4 materialized intermediates of the jnp path
 (mask-mul, sum, count-div, where).
 
-Grid (G, n_tiles); block [1, M, bd]. The mask [G, M] rides in SMEM.
+Grid (G, n_tiles); block [1, M, bd]. The whole mask [G, M] rides in
+SMEM and is read one scalar at a time.
 """
 from __future__ import annotations
 
@@ -21,12 +22,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _group_mean_kernel(mask_ref, x_ref, o_ref, *, m: int):
+    gi = pl.program_id(0)
+    # the mask is read one SMEM scalar at a time (a vector load from
+    # SMEM does not lower); each row is scaled by its own scalar
+    num = x_ref[0, 0:1, :].astype(jnp.float32) * mask_ref[gi, 0]
+    den = mask_ref[gi, 0]
+    for j in range(1, m):
+        num = num + x_ref[0, j:j + 1, :].astype(jnp.float32) \
+            * mask_ref[gi, j]
+        den = den + mask_ref[gi, j]
+    mean = num / jnp.maximum(den, 1.0)               # [1, bd]
     x = x_ref[0].astype(jnp.float32)                 # [M, bd]
-    mask = mask_ref[0]                                # [M] f32 in SMEM
-    mk = jnp.asarray([mask[i] for i in range(m)], jnp.float32)[:, None]
-    num = jnp.sum(x * mk, axis=0, keepdims=True)     # [1, bd]
-    den = jnp.sum(mk)
-    mean = num / jnp.maximum(den, 1.0)
     out = jnp.where(den > 0, jnp.broadcast_to(mean, x.shape), x)
     o_ref[0] = out.astype(o_ref.dtype)
 
@@ -46,8 +52,7 @@ def group_mean_fwd(x: jax.Array, mask: jax.Array, block_d: int = 2048,
         kernel,
         grid=(g, nt),
         in_specs=[
-            pl.BlockSpec((1, m), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # whole [G, M]
             pl.BlockSpec((1, m, bd), lambda i, j: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, m, bd), lambda i, j: (i, 0, j)),

@@ -1,14 +1,16 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute through the Pallas
-interpreter (``interpret=True`` — the kernel body runs in Python,
-semantics-exact); on TPU set ``REPRO_PALLAS_INTERPRET=0`` (or rely on
-the default platform check) for compiled Mosaic kernels.
+The platform alone picks how a kernel runs: on a TPU it is compiled by
+Mosaic; on any other backend it runs through the Pallas interpreter
+(``interpret=True`` — the kernel body runs in Python, semantics-exact),
+which is how the CPU test suite checks it against ``ref.py``. The
+kernels compile for v5e at the published widths of the registry's
+models (``tests/test_tpu_compile.py``). The paged decode kernel is the
+one on a main path: ``DecodeServer`` calls it on every decode step.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +26,6 @@ Array = jax.Array
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 
@@ -59,16 +58,17 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
 @jax.jit
 def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
                            block_tables: Array, lengths: Array) -> Array:
-    """q [b,h,d]; pages [nb,bs,kvh,d]; block_tables [b,nblk]; lengths [b]
+    """q [b,h,d]; pages [nb,kvh,bs,d]; block_tables [b,nblk]; lengths [b]
     -> [b,h,d].
 
     TPU: split-K kernel gathering pages via the scalar-prefetched block
-    table. CPU/interpret: gather+dense fallback (running the kernel
-    through the Python interpreter per page would be the slow path;
-    the gathered einsum is semantics-exact).
+    table. Other backends: the gathered dense einsum (running the
+    kernel through the Python interpreter per page would be the slow
+    path; the gathered einsum is semantics-exact).
     """
     _check(q.ndim == 3 and k_pages.ndim == 4, "bad ranks")
     _check(q.shape[2] == k_pages.shape[3], "head_dim mismatch")
+    _check(q.shape[1] % k_pages.shape[1] == 0, "GQA heads must divide")
     _check(k_pages.shape == v_pages.shape, "k/v pages mismatch")
     _check(block_tables.ndim == 2 and block_tables.shape[0] == q.shape[0],
            "block_tables must be [b, nblk]")

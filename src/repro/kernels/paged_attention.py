@@ -1,7 +1,7 @@
 """Pallas TPU paged decode attention: one query token vs a block-paged cache.
 
-The serving tier stores KV in fixed-size *blocks* (``[num_blocks,
-block_size, kvh, d]``) owned by a host-side allocator; each session
+The serving tier stores KV in fixed-size *blocks* (``[num_blocks, kvh,
+block_size, d]``) owned by a host-side allocator; each session
 holds an ordered *block table* row mapping its logical positions to
 physical blocks (``serve/paged_cache.py``). This kernel is the paged
 variant of ``decode_attention.py``: the same split-K flash recurrence
@@ -14,7 +14,12 @@ including the garbage tail of a partially-filled last block and any
 scratch-page padding rows of the table) are masked by the same
 lane-position iota as the dense kernel.
 
-On CPU/interpret the production path does not run the kernel at all:
+One KV head's page is a ``(block_size, d)`` tile, so the page block
+``(1, 1, block_size, d)`` meets the TPU tiling rule (last two block dims
+equal to the array's or multiples of (8, 128)) for any ``kvh``; keep
+``block_size`` a multiple of the bf16 sublane tile (16).
+
+On the CPU the serving path does not run the kernel at all:
 ``gather_dense_decode`` materializes the session's pages into a dense
 cache view and applies the exact einsum/softmax used by the dense
 decode path (``interpret=True`` on the kernel itself is kept for
@@ -50,8 +55,8 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(k_start < length)
     def _step():
         q = q_ref[0].astype(jnp.float32)             # [g, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # [bs, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # [bs, d]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [g, bs]
@@ -77,10 +82,10 @@ def paged_decode_attention_fwd(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
                                lengths: jax.Array,
                                interpret: bool = False) -> jax.Array:
-    """q [b,h,d]; pages [nb,bs,kvh,d]; block_tables [b,nblk]; lengths [b]
+    """q [b,h,d]; pages [nb,kvh,bs,d]; block_tables [b,nblk]; lengths [b]
     -> [b,h,d]."""
     b, h, d = q.shape
-    bs, kvh = k_pages.shape[1], k_pages.shape[2]
+    kvh, bs = k_pages.shape[1], k_pages.shape[2]
     nblk = block_tables.shape[1]
     g = h // kvh
     scale = 1.0 / np.sqrt(d)
@@ -89,8 +94,8 @@ def paged_decode_attention_fwd(q: jax.Array, k_pages: jax.Array,
     kernel = functools.partial(_paged_decode_kernel, bs=bs, scale=scale,
                                nblk=nblk, kvh=kvh)
     page_spec = pl.BlockSpec(
-        (1, bs, 1, d),
-        lambda i, kk, bt, ln: (bt[i // kvh, kk], 0, i % kvh, 0))
+        (1, 1, bs, d),
+        lambda i, kk, bt, ln: (bt[i // kvh, kk], i % kvh, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,       # block_tables, lengths
         grid=(b * kvh, nblk),
@@ -119,7 +124,7 @@ def paged_decode_attention_fwd(q: jax.Array, k_pages: jax.Array,
 def gather_dense_decode(q: jax.Array, k_pages: jax.Array,
                         v_pages: jax.Array, block_tables: jax.Array,
                         lengths: jax.Array) -> jax.Array:
-    """CPU/interpret fallback: gather the session's pages into a dense
+    """CPU path: gather the session's pages into a dense
     [b, nblk*bs, kvh, d] view and run the dense decode einsum.
 
     Mirrors ``layers._sdpa_chunk`` op-for-op (fp32 scores/softmax, probs
@@ -127,14 +132,14 @@ def gather_dense_decode(q: jax.Array, k_pages: jax.Array,
     numerically aligned with the dense-cache path on identical shapes.
     """
     b, h, d = q.shape
-    bs, kvh = k_pages.shape[1], k_pages.shape[2]
     nblk = block_tables.shape[1]
-    s = nblk * bs
+    kvh, s = k_pages.shape[1], nblk * k_pages.shape[2]
     g = h // kvh
     scale = 1.0 / np.sqrt(d)
 
-    k = k_pages[block_tables].reshape(b, s, kvh, d)
-    v = v_pages[block_tables].reshape(b, s, kvh, d)
+    # [b, nblk, kvh, bs, d] -> dense [b, nblk*bs, kvh, d], position order
+    k = k_pages[block_tables].swapaxes(2, 3).reshape(b, s, kvh, d)
+    v = v_pages[block_tables].swapaxes(2, 3).reshape(b, s, kvh, d)
     qg = q.reshape(b, 1, kvh, g, d)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale

@@ -52,17 +52,17 @@ def decode_attention_ref(q: Array, k_cache: Array, v_cache: Array,
 
 def paged_decode_attention_ref(q: Array, k_pages: Array, v_pages: Array,
                                block_tables: Array, lengths: Array) -> Array:
-    """q [b,h,d]; pages [nb,bs,kvh,d]; block_tables [b,nblk]; lengths [b].
+    """q [b,h,d]; pages [nb,kvh,bs,d]; block_tables [b,nblk]; lengths [b].
 
     Gathers each session's pages into a dense [b, nblk*bs, kvh, d] cache
     (block-table order == position order) and defers to the dense decode
     oracle — the semantic ground truth for the paged kernel.
     """
     b = q.shape[0]
-    bs, kvh, d = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
+    kvh, bs, d = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
     s = block_tables.shape[1] * bs
-    k = k_pages[block_tables].reshape(b, s, kvh, d)
-    v = v_pages[block_tables].reshape(b, s, kvh, d)
+    k = jnp.swapaxes(k_pages[block_tables], 2, 3).reshape(b, s, kvh, d)
+    v = jnp.swapaxes(v_pages[block_tables], 2, 3).reshape(b, s, kvh, d)
     return decode_attention_ref(q, k, v, lengths)
 
 

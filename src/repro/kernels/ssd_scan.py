@@ -8,7 +8,7 @@ in chunk-parallel form: grid (batch*head, n_chunks) with the chunk axis
 innermost and the running state H [dk, dv] carried in f32 VMEM scratch.
 Per chunk (all in VMEM, MXU matmuls):
 
-    cum_i   = cumsum(a)                         # [c]
+    cum_i   = cumsum(a)    (a triangular matmul)  # [c]
     intra   = (q k^T * exp(cum_i - cum_j) * causal) v        (3 matmuls)
     inter   = (q . H) * exp(cum_i)
     H'      = exp(cum_c) H + (k * exp(cum_c - cum_j))^T v
@@ -27,8 +27,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(q_ref, k_ref, v_ref, a_ref, h0_ref, y_ref, hout_ref,
-                h_ref, *, chunk: int, nchunks: int):
+def _ssd_kernel(q_ref, k_ref, v_ref, ar_ref, ac_ref, h0_ref, y_ref,
+                hout_ref, h_ref, *, chunk: int, nchunks: int):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
@@ -38,25 +38,36 @@ def _ssd_kernel(q_ref, k_ref, v_ref, a_ref, h0_ref, y_ref, hout_ref,
     q = q_ref[0].astype(jnp.float32)                 # [c, dk]
     k = k_ref[0].astype(jnp.float32)                 # [c, dk]
     v = v_ref[0].astype(jnp.float32)                 # [c, dv]
-    a = a_ref[0].astype(jnp.float32)                 # [c]
+    a_row = ar_ref[0].astype(jnp.float32)            # [1, c]
+    a_col = ac_ref[0].astype(jnp.float32)            # [c, 1]
     h = h_ref[...]                                   # [dk, dv]
 
-    cum = jnp.cumsum(a)                              # [c]
-    total = cum[-1]
-    qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [c, c]
-    decay = cum[:, None] - cum[None, :]
+    # cumsum as triangular matmuls (Mosaic has no scan primitive); the
+    # row and column copies of log_a avoid an in-kernel transpose
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    gate = jnp.where(rows >= cols, jnp.exp(jnp.minimum(decay, 0.0)), 0.0)
+    causal = rows >= cols
+    tril = causal.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    cum_col = jax.lax.dot_general(tril, a_col, (((1,), (0,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)
+    cum_row = jax.lax.dot_general(a_row, tril, (((1,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)
+    total = jnp.sum(a_row)
+    qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # [c, c]
+    decay = cum_col - cum_row                        # [c, c]
+    gate = jnp.where(causal, jnp.exp(jnp.minimum(decay, 0.0)), 0.0)
     y_intra = jax.lax.dot_general(qk * gate, v, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     y_inter = jax.lax.dot_general(q, h, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32) \
-        * jnp.exp(cum)[:, None]
+        * jnp.exp(cum_col)
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    w = jnp.exp(total - cum)[:, None]                # [c, 1]
+    w = jnp.exp(total - cum_col)                     # [c, 1]
     h_new = h * jnp.exp(total) + jax.lax.dot_general(
         k * w, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -84,7 +95,10 @@ def ssd_scan_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
     qr = q.reshape(b * nh, s, dk)
     kr = k.reshape(b * nh, s, dk)
     vr = v.reshape(b * nh, s, dv)
-    ar = log_a.reshape(b * nh, s)
+    # log_a rides in twice, as a [1, c] row block and a [c, 1] column
+    # block: both are tile-legal (a unit dim equal to the array's)
+    ar_row = log_a.reshape(b * nh, 1, s)
+    ar_col = log_a.reshape(b * nh, s, 1)
     hr = h0.reshape(b * nh, dk, dv)
 
     kernel = functools.partial(_ssd_kernel, chunk=c, nchunks=nchunks)
@@ -95,7 +109,8 @@ def ssd_scan_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, c, dk), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, c, dk), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, c, dv), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, c), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, c, 1), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, dk, dv), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
@@ -108,5 +123,5 @@ def ssd_scan_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(qr, kr, vr, ar, hr)
+    )(qr, kr, vr, ar_row, ar_col, hr)
     return (y.reshape(b, nh, s, dv), h_final.reshape(b, nh, dk, dv))

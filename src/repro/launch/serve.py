@@ -17,14 +17,16 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
-from repro.serve import (DecodeServer, ServeConfig, run_sequential,
-                         serving_params_from_checkpoint)
+from repro.serve import (DecodeServer, ServeConfig, Session,
+                         run_sequential, serving_params_from_checkpoint)
 
 PAGED = ("dense", "vlm", "audio", "moe")
 
@@ -39,7 +41,7 @@ def _summarize(tag, sessions, elapsed):
           f"per-token p50 {p50:.1f}ms p99 {p99:.1f}ms")
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="starcoder2-3b", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")
@@ -49,7 +51,8 @@ def main(argv=None) -> int:
                     help="mixed prompt lengths in [1, prompt_len]")
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
-    ap.add_argument("--block-size", type=int, default=8)
+    ap.add_argument("--block-size", type=int,
+                    default=ServeConfig.block_size)
     ap.add_argument("--num-blocks", type=int, default=None,
                     help="KV pool size (default: a full batch's worst case)")
     ap.add_argument("--sequential", action="store_true",
@@ -60,12 +63,19 @@ def main(argv=None) -> int:
     ap.add_argument("--swap-demo", action="store_true",
                     help="identity hot-swap mid-drain (zero-drop demo)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def serve(args: argparse.Namespace
+          ) -> Tuple[List[Session], Optional[DecodeServer]]:
+    """Drain ``args.sessions`` synthetic sessions; returns the finished
+    sessions and the drained engine (None on the sequential path)."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     rng = np.random.default_rng(args.seed)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # jitted so each leaf is drawn straight into its dtype: eager init
+    # holds a float32 copy of the largest stacked leaf on the device
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
 
     ckpt = None
     if args.ckpt_dir:
@@ -94,7 +104,7 @@ def main(argv=None) -> int:
                               pad_len=args.prompt_len)
         _summarize("sequential", done, time.perf_counter() - t0)
         print("[serve] sample:", done[0].generated[:16])
-        return 0
+        return done, None
 
     need = -(-(args.prompt_len + args.gen) // args.block_size)
     num_blocks = args.num_blocks or 1 + need * args.max_batch
@@ -123,6 +133,12 @@ def main(argv=None) -> int:
     if srv.swap_log:
         print("[serve] swap log:", srv.swap_log)
     print("[serve] sample:", srv.finished[0].generated[:16])
+    return srv.finished, srv
+
+
+def main(argv=None) -> int:
+    use_compile_cache()
+    serve(parse_args(argv))
     return 0
 
 

@@ -62,10 +62,20 @@ from repro.core.moshpit import plan_grid
 from repro.core.replan import (plan_membership_change,
                                validate_membership_schedule)
 from repro.data.synthetic import lm_token_stream
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.runtime.fault import HealthTracker, StragglerPolicy
 from repro.runtime.lifecycle import CHURN_MODELS, build_lifecycle
 from repro.runtime.metrics import MetricsLogger
+
+
+def jit_train_step(model: Model, grid, lr: float, pipeline):
+    """The jitted FL train step. The state (argument 0) is donated: the
+    step rewrites every leaf, and the checkpointer copies to the host
+    synchronously, so no caller reads a state after passing it in."""
+    return jax.jit(make_fl_train_step(model, grid, lr=lr,
+                                      pipeline=pipeline),
+                   donate_argnums=0)
 
 
 def main(argv=None) -> int:
@@ -198,6 +208,7 @@ def main(argv=None) -> int:
         ap.error("--peer-hosts is the socket transport's address "
                  "book; pass --transport socket")
 
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     n_peers = args.peers
@@ -211,8 +222,7 @@ def main(argv=None) -> int:
                               compress=args.compress)
     if pipeline.stage_names:
         print(f"[train] wire stages: {', '.join(pipeline.stage_names)}")
-    step_fn = jax.jit(make_fl_train_step(
-        model, grid, lr=args.lr, pipeline=pipeline))
+    step_fn = jit_train_step(model, grid, args.lr, pipeline)
 
     state = init_fl_state(model, n_peers, jax.random.PRNGKey(args.seed),
                           pipeline=pipeline)
@@ -334,8 +344,7 @@ def main(argv=None) -> int:
                   f"{change.old_n} -> {change.new_n} peers, "
                   f"grid={grid.dims} "
                   f"(+{change.n_joiners} joiners)")
-            step_fn = jax.jit(make_fl_train_step(
-                model, grid, lr=args.lr, pipeline=pipeline))
+            step_fn = jit_train_step(model, grid, args.lr, pipeline)
             stream = lm_token_stream(
                 cfg.vocab_size,
                 n_peers * args.local_steps * args.batch, args.seq,
@@ -375,6 +384,7 @@ def main(argv=None) -> int:
             # a straggler keeps its update but misses its group mean
             state, metrics = step_fn(state, batch, jnp.asarray(u),
                                      jnp.asarray(a))
+        jax.block_until_ready((state, metrics))
         dt = time.time() - t0
         if transcript is not None:
             pipeline.record_transcript(ledger, transcript, n_act,
@@ -396,8 +406,7 @@ def main(argv=None) -> int:
                           f"{grid.dims} -> {proposal.dims}")
                     grid = proposal
                     pipeline = pipeline.with_plan(grid)
-                    step_fn = jax.jit(make_fl_train_step(
-                        model, grid, lr=args.lr, pipeline=pipeline))
+                    step_fn = jit_train_step(model, grid, args.lr, pipeline)
                     if placement_policy is not None:
                         # dims changed: re-emit the permutation for the
                         # new grid on the next observe
@@ -412,8 +421,7 @@ def main(argv=None) -> int:
                           f"{moved}/{grid.n_peers} peers moved")
                     grid = target
                     pipeline = pipeline.with_plan(grid)
-                    step_fn = jax.jit(make_fl_train_step(
-                        model, grid, lr=args.lr, pipeline=pipeline))
+                    step_fn = jit_train_step(model, grid, args.lr, pipeline)
         else:
             pipeline.record_iteration(ledger, int(a.sum()),
                                       peer_model_bytes)
@@ -425,7 +433,7 @@ def main(argv=None) -> int:
                         * args.batch * args.seq,
                         sim_s=(transcript.iteration_s
                                if transcript is not None else None),
-                        loss=float(metrics["loss"]))
+                        loss=float(metrics["loss"]), step_s=dt)
         if (t + 1) % 5 == 0 or t == start:
             sim = (f" sim={transcript.iteration_s*1e3:.0f}ms"
                    if transcript is not None else "")

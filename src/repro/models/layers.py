@@ -194,7 +194,7 @@ def attention_decode_paged(params, x: Array, cfg: ModelConfig,
                            ) -> Tuple[Array, Array, Array]:
     """Single-token decode against a paged KV pool (serving tier).
 
-    x:[b,1,d]; pages [num_blocks, bs, kvh, hd] (this layer's slice of the
+    x:[b,1,d]; pages [num_blocks, kvh, bs, hd] (this layer's slice of the
     pool); block_tables [b, nblk] maps each session's logical block k to
     a physical page; pos [b] = tokens already cached. The new K/V row is
     scattered into page ``block_tables[i, pos // bs]`` slot ``pos % bs``;
@@ -206,7 +206,7 @@ def attention_decode_paged(params, x: Array, cfg: ModelConfig,
     """
     b = x.shape[0]
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     q = (x @ params["wq"]).reshape(b, 1, h, hd)
     k = (x @ params["wk"]).reshape(b, 1, kvh, hd)
     v = (x @ params["wv"]).reshape(b, 1, kvh, hd)
@@ -217,8 +217,8 @@ def attention_decode_paged(params, x: Array, cfg: ModelConfig,
     slot = pos % bs
     # duplicate (blk, slot) targets only occur on the scratch page 0
     # (inactive rows) — the undefined winner there is never read.
-    k_pages = k_pages.at[blk, slot].set(k[:, 0])
-    v_pages = v_pages.at[blk, slot].set(v[:, 0])
+    k_pages = k_pages.at[blk, :, slot].set(k[:, 0])   # [b, kvh, hd]
+    v_pages = v_pages.at[blk, :, slot].set(v[:, 0])
 
     from repro.kernels import ops as kops
     out = kops.paged_decode_attention(q[:, 0], k_pages, v_pages,

@@ -426,14 +426,15 @@ PAGED_FAMILIES = ("dense", "vlm", "audio", "moe")
 def init_paged_cache(cfg: ModelConfig, num_blocks: int,
                      block_size: int) -> PyTree:
     """Zeroed paged KV pool shared by all sessions: ``[L, num_blocks,
-    block_size, kvh, hd]`` per tensor. Block 0 is the engine's scratch
+    kvh, block_size, hd]`` per tensor (one KV head's page is a
+    ``(block_size, hd)`` tile — the paged kernel's block). Block 0 is the engine's scratch
     page (inactive batch rows write there). KV-cache families only —
     ssm/hybrid state is O(1)/O(window) and needs no paging."""
     if cfg.family not in PAGED_FAMILIES:
         raise ValueError(
             f"paged KV serving needs a KV-cache family, got {cfg.family}")
     dt = jnp.dtype(cfg.dtype)
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads,
+    shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size,
              cfg.head_dim)
     return {"k_pages": jnp.zeros(shape, dt), "v_pages": jnp.zeros(shape, dt)}
 

@@ -1,7 +1,7 @@
 """Paged KV cache: host-side block allocator + device scatter helpers.
 
 The serving pool is one tensor pair per model (``transformer.
-init_paged_cache``): ``[L, num_blocks, block_size, kvh, hd]``. Sessions
+init_paged_cache``): ``[L, num_blocks, kvh, block_size, hd]``. Sessions
 own disjoint sets of physical blocks; a per-session *block table* row
 lists them in logical-position order, so position ``p`` lives at page
 ``table[p // block_size]`` slot ``p % block_size``. Block 0 is the
@@ -86,7 +86,7 @@ def write_prefill_to_pages(pages: Dict[str, Array], k: Array, v: Array,
     non-scratch page, so scatter collisions only hit scratch.
     """
     k_pages = pages["k_pages"]
-    bs = k_pages.shape[2]
+    bs = k_pages.shape[3]
     L, b, s = k.shape[0], k.shape[1], k.shape[2]
     nblk = -(-s // bs)
     if block_tables.shape[1] < nblk:
@@ -96,8 +96,9 @@ def write_prefill_to_pages(pages: Dict[str, Array], k: Array, v: Array,
     if s_pad != s:
         pad = ((0, 0), (0, 0), (0, s_pad - s), (0, 0), (0, 0))
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-    kb = k.reshape(L, b, nblk, bs, *k.shape[3:])
-    vb = v.reshape(L, b, nblk, bs, *v.shape[3:])
+    # [L, b, nblk, bs, kvh, hd] -> page layout [L, b, nblk, kvh, bs, hd]
+    kb = k.reshape(L, b, nblk, bs, *k.shape[3:]).swapaxes(3, 4)
+    vb = v.reshape(L, b, nblk, bs, *v.shape[3:]).swapaxes(3, 4)
     bt = block_tables[:, :nblk]
     return {"k_pages": k_pages.at[:, bt].set(kb),
             "v_pages": pages["v_pages"].at[:, bt].set(vb)}
@@ -108,8 +109,8 @@ def gather_session_cache(pages: Dict[str, Array], table: List[int],
     """Debug/test helper: materialize one session's dense KV view
     ``[L, 1, nblk*bs, kvh, hd]`` from its block-table row."""
     bt = jnp.asarray(table, jnp.int32)
-    k = pages["k_pages"][:, bt]            # [L, nblk, bs, kvh, hd]
-    v = pages["v_pages"][:, bt]
+    k = pages["k_pages"][:, bt].swapaxes(2, 3)   # [L, nblk, bs, kvh, hd]
+    v = pages["v_pages"][:, bt].swapaxes(2, 3)
     L, nblk, bsz = k.shape[0], k.shape[1], k.shape[2]
     return {"k": k.reshape(L, 1, nblk * bsz, *k.shape[3:]),
             "v": v.reshape(L, 1, nblk * bsz, *v.shape[3:])}

@@ -110,7 +110,7 @@ def test_decode_attention_odd_lengths(s, block_k):
 def test_paged_decode_attention_sweep(b, nblk, bs, h, kvh, d, dtype):
     nb = 1 + b * nblk
     q = _arr((b, h, d), dtype)
-    kp, vp = _arr((nb, bs, kvh, d), dtype), _arr((nb, bs, kvh, d), dtype)
+    kp, vp = _arr((nb, kvh, bs, d), dtype), _arr((nb, kvh, bs, d), dtype)
     bt = jnp.asarray(RNG.permutation(np.arange(1, nb)).reshape(b, nblk),
                      jnp.int32)
     lens = jnp.asarray(RNG.integers(1, nblk * bs + 1, size=(b,)), jnp.int32)

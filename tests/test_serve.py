@@ -54,8 +54,8 @@ def _paged_inputs(seed=0, b=3, nblk=4, bs=8, kvh=2, g=4, d=16):
     rng = np.random.default_rng(seed)
     nb = 1 + b * nblk
     q = jnp.asarray(rng.normal(size=(b, kvh * g, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, kvh, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, kvh, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, kvh, bs, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, kvh, bs, d)), jnp.float32)
     bt = jnp.asarray(rng.permutation(np.arange(1, nb))
                      .reshape(b, nblk), jnp.int32)
     lens = jnp.asarray([1, bs * nblk, bs * 2 + 3][:b], jnp.int32)
