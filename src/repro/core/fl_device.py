@@ -186,52 +186,60 @@ def make_fl_train_step(model: Model, grid: GridPlan, lr: float = 0.1,
             p, m = carry
 
             def micro(acc, mb_batch):
-                loss, grads = jax.value_and_grad(model.loss)(p, mb_batch)
-                acc = (jax.tree.map(jnp.add, acc[0], grads),
-                       acc[1] + loss)
+                with jax.named_scope("fl.grad"):
+                    loss, grads = jax.value_and_grad(model.loss)(p, mb_batch)
+                with jax.named_scope("fl.opt"):
+                    acc = (jax.tree.map(jnp.add, acc[0], grads),
+                           acc[1] + loss)
                 return acc, None
 
-            zeros = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), p)
+            with jax.named_scope("fl.opt"):
+                zeros = jax.tree.map(
+                    lambda x: jnp.zeros(x.shape, jnp.float32), p)
             (gsum, lsum), _ = jax.lax.scan(
                 micro, (zeros, jnp.zeros((), jnp.float32)), step_batch)
-            n_micro = jax.tree.leaves(step_batch)[0].shape[0]
-            grads = jax.tree.map(lambda g: g / n_micro, gsum)
-            p, m = momentum_sgd_step(p, m, grads, lr, mu)
-            return (p, m), lsum / n_micro
+            with jax.named_scope("fl.opt"):
+                n_micro = jax.tree.leaves(step_batch)[0].shape[0]
+                grads = jax.tree.map(lambda g: g / n_micro, gsum)
+                p, m = momentum_sgd_step(p, m, grads, lr, mu)
+                return (p, m), lsum / n_micro
 
         (params, momentum), losses = jax.lax.scan(
             one_step, (params, momentum), peer_batch)
-        return params, momentum, jnp.mean(losses)
+        with jax.named_scope("fl.opt"):
+            return params, momentum, jnp.mean(losses)
 
     def fl_train_step(state, batch, mask=None, agg_mask=None):
         params, momentum = state["params"], state["momentum"]
         new_p, new_m, loss = jax.vmap(peer_local_update)(
             params, momentum, batch)
-        if mask is not None:
-            # churn: masked-out peers carry previous state forward
-            sel = lambda new, old: jax.tree.map(
-                lambda a, b: jnp.where(
-                    mask.reshape((-1,) + (1,) * (a.ndim - 1)) > 0, a, b),
-                new, old)
-            new_p, new_m = sel(new_p, params), sel(new_m, momentum)
-        new_state = {"params": new_p, "momentum": new_m,
-                     "step": state["step"] + 1}
+        with jax.named_scope("fl.opt"):
+            if mask is not None:
+                # churn: masked-out peers carry previous state forward
+                sel = lambda new, old: jax.tree.map(
+                    lambda a, b: jnp.where(
+                        mask.reshape((-1,) + (1,) * (a.ndim - 1)) > 0, a, b),
+                    new, old)
+                new_p, new_m = sel(new_p, params), sel(new_m, momentum)
+            new_state = {"params": new_p, "momentum": new_m,
+                         "step": state["step"] + 1}
+            metrics = {"loss": jnp.mean(loss)}
         if aggregate:
             if pipeline.stages and "pipe" not in state:
                 raise ValueError(
                     "pipeline has wire stages; build the state with "
                     "init_fl_state(..., pipeline=pipeline)")
-            m = agg_mask if agg_mask is not None else mask
-            if m is None:
-                m = jnp.ones((grid.capacity,), jnp.float32)
-            key = jax.random.fold_in(jax.random.PRNGKey(0), state["step"])
-            agg, new_pipe = pipeline({"p": new_p, "m": new_m},
-                                     state.get("pipe", {}), m, key)
+            with jax.named_scope("fl.aggregate"):
+                m = agg_mask if agg_mask is not None else mask
+                if m is None:
+                    m = jnp.ones((grid.capacity,), jnp.float32)
+                key = jax.random.fold_in(jax.random.PRNGKey(0),
+                                         state["step"])
+                agg, new_pipe = pipeline({"p": new_p, "m": new_m},
+                                         state.get("pipe", {}), m, key)
             new_state["params"], new_state["momentum"] = agg["p"], agg["m"]
             if "pipe" in state:
                 new_state["pipe"] = new_pipe
-        metrics = {"loss": jnp.mean(loss)}
         return new_state, metrics
 
     return fl_train_step

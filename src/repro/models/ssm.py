@@ -263,23 +263,24 @@ def _mlstm_normalize(y_aug: Array) -> Array:
 
 
 def mlstm_block(params, x: Array, cfg: ModelConfig, h0: Array = None):
-    b, s, d = x.shape
-    inner, hd, nh = mlstm_dims(cfg)
-    q, k, v, i, f, z = _mlstm_qkvif(params, x, cfg)
-    # normalizer trick: append ones column to v, scaled by input gate
-    v_aug = jnp.concatenate([v, jnp.ones_like(v[..., :1])], axis=-1)
-    v_aug = v_aug * i[..., None].astype(v.dtype)
-    if h0 is None:
-        h0 = jnp.zeros((b, nh, hd, hd + 1), jnp.float32)
-    if cfg.attn_impl == "pallas":
-        from repro.kernels import ops as kops
-        y_aug, h_final = kops.ssd_scan(q, k, v_aug, f, h0)
-    else:
-        y_aug, h_final = chunked_linear_scan(q, k, v_aug, f, h0)
-    y = _mlstm_normalize(y_aug.astype(jnp.float32)).astype(x.dtype)
-    y = y.transpose(0, 2, 1, 3).reshape(b, s, inner)
-    y = y * jax.nn.silu(z)
-    return y @ params["out_proj"], h_final
+    with jax.named_scope("mlstm"):
+        b, s, d = x.shape
+        inner, hd, nh = mlstm_dims(cfg)
+        q, k, v, i, f, z = _mlstm_qkvif(params, x, cfg)
+        # normalizer trick: append ones column to v, scaled by input gate
+        v_aug = jnp.concatenate([v, jnp.ones_like(v[..., :1])], axis=-1)
+        v_aug = v_aug * i[..., None].astype(v.dtype)
+        if h0 is None:
+            h0 = jnp.zeros((b, nh, hd, hd + 1), jnp.float32)
+        if cfg.attn_impl == "pallas":
+            from repro.kernels import ops as kops
+            y_aug, h_final = kops.ssd_scan(q, k, v_aug, f, h0)
+        else:
+            y_aug, h_final = chunked_linear_scan(q, k, v_aug, f, h0)
+        y = _mlstm_normalize(y_aug.astype(jnp.float32)).astype(x.dtype)
+        y = y.transpose(0, 2, 1, 3).reshape(b, s, inner)
+        y = y * jax.nn.silu(z)
+        return y @ params["out_proj"], h_final
 
 
 def mlstm_decode_step(params, x: Array, cfg: ModelConfig, state: Array):
@@ -339,19 +340,20 @@ def slstm_cell(params, xt: Array, carry, cfg: ModelConfig):
 
 def slstm_block(params, x: Array, cfg: ModelConfig, carry=None):
     """x: [b, S, d] -> [b, S, d]; sequential scan over time."""
-    b, s, d = x.shape
-    xin = x @ params["w_in"]                             # [b, S, 4d]
-    if carry is None:
-        zeros = jnp.zeros((b, d), jnp.float32)
-        carry = (zeros, zeros, zeros, jnp.full((b, d), -1e30, jnp.float32))
+    with jax.named_scope("slstm"):
+        b, s, d = x.shape
+        xin = x @ params["w_in"]                             # [b, S, 4d]
+        if carry is None:
+            zeros = jnp.zeros((b, d), jnp.float32)
+            carry = (zeros, zeros, zeros, jnp.full((b, d), -1e30, jnp.float32))
 
-    def step(carry, xt):
-        new = slstm_cell(params, xt, carry, cfg)
-        return new, new[0]
+        def step(carry, xt):
+            new = slstm_cell(params, xt, carry, cfg)
+            return new, new[0]
 
-    carry, hs = jax.lax.scan(step, carry, jnp.moveaxis(xin, 1, 0))
-    y = jnp.moveaxis(hs, 0, 1).astype(x.dtype)           # [b, S, d]
-    return y @ params["out_proj"], carry
+        carry, hs = jax.lax.scan(step, carry, jnp.moveaxis(xin, 1, 0))
+        y = jnp.moveaxis(hs, 0, 1).astype(x.dtype)           # [b, S, d]
+        return y @ params["out_proj"], carry
 
 
 def slstm_decode_step(params, x: Array, cfg: ModelConfig, carry):

@@ -210,10 +210,11 @@ def forward(params: PyTree, tokens: Array, cfg: ModelConfig,
                      "attn_k": kvs[0], "attn_v": kvs[1],
                      "pos": jnp.full((b,), s, jnp.int32)}
 
-    h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    if prefix_embeds is not None:
-        h = h[:, -s_text:]
-    logits = L.unembed(params["embedding"], h, cfg)
+    with jax.named_scope("lm_head"):
+        h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        if prefix_embeds is not None:
+            h = h[:, -s_text:]
+        logits = L.unembed(params["embedding"], h, cfg)
     return logits, aux_total, cache
 
 
@@ -223,18 +224,19 @@ def lm_loss(params: PyTree, batch: Dict[str, Array], cfg: ModelConfig,
     logits, aux, _ = forward(params, batch["tokens"], cfg,
                              prefix_embeds=batch.get("prefix_embeds"))
     targets = batch["labels"]
-    # one-hot contraction instead of take_along_axis: with vocab-sharded
-    # logits this reduces to a tiny psum instead of a logits all-gather
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=logits.dtype)
-    picked = jnp.einsum("...v,...v->...", logits, onehot)
-    nll = lse - picked
-    mask = batch.get("loss_mask")
-    if mask is None:
-        loss = jnp.mean(nll)
-    else:
-        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return loss + aux_coef * aux
+    with jax.named_scope("lm_head"):
+        # one-hot contraction instead of take_along_axis: with vocab-sharded
+        # logits this reduces to a tiny psum instead of a logits all-gather
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=logits.dtype)
+        picked = jnp.einsum("...v,...v->...", logits, onehot)
+        nll = lse - picked
+        mask = batch.get("loss_mask")
+        if mask is None:
+            loss = jnp.mean(nll)
+        else:
+            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        return loss + aux_coef * aux
 
 
 # ---------------------------------------------------------------------------
