@@ -5,8 +5,9 @@ Mosaic; on any other backend it runs through the Pallas interpreter
 (``interpret=True`` — the kernel body runs in Python, semantics-exact),
 which is how the CPU test suite checks it against ``ref.py``. The
 kernels compile for v5e at the published widths of the registry's
-models (``tests/test_tpu_compile.py``). The paged decode kernel is the
-one on a main path: ``DecodeServer`` calls it on every decode step.
+models (``tests/test_tpu_compile.py``). Two are on main paths:
+``DecodeServer`` calls the paged decode kernel on every decode step, and
+every xLSTM sLSTM block runs ``slstm_scan``, in training and prefill.
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.group_mean import group_mean_fwd
 from repro.kernels.paged_attention import (gather_dense_decode,
                                            paged_decode_attention_fwd)
+from repro.kernels.slstm_scan import slstm_scan as _slstm_scan
 from repro.kernels.ssd_scan import ssd_scan_fwd
 
 Array = jax.Array
@@ -85,6 +87,19 @@ def ssd_scan(q: Array, k: Array, v: Array, log_a: Array, h0: Array):
     _check(q.shape == k.shape, "q/k shape mismatch")
     _check(q.shape[:3] == v.shape[:3], "v batch/seq mismatch")
     return ssd_scan_fwd(q, k, v, log_a, h0, interpret=_interpret())
+
+
+@jax.jit
+def slstm_scan(xin: Array, r_rec: Array, bias: Array, s0: Array):
+    """sLSTM over a sequence, differentiable; see slstm_scan.py.
+    xin [b,S,4d]; r_rec [nh,hd,4hd]; bias [4d]; s0 [b,4d] the carry
+    (h, c, n, m) -> (h [b,S,d] f32, final carry [b,4d])."""
+    _check(xin.ndim == 3 and s0.shape == (xin.shape[0], xin.shape[2]),
+           "xin [b,S,4d] and s0 [b,4d]")
+    _check(r_rec.ndim == 3 and 4 * r_rec.shape[0] * r_rec.shape[1]
+           == xin.shape[2] and r_rec.shape[2] == 4 * r_rec.shape[1],
+           "r_rec must be [nh, hd, 4hd] with 4*nh*hd == 4d")
+    return _slstm_scan(xin, r_rec, bias, s0, _interpret())
 
 
 @jax.jit

@@ -100,3 +100,36 @@ def group_mean_ref(x: Array, mask: Array) -> Array:
     mean = num / jnp.maximum(den, 1.0)
     out = jnp.where(den > 0, mean, x.astype(jnp.float32))
     return jnp.broadcast_to(out, x.shape).astype(x.dtype)
+
+
+def slstm_scan_ref(xin: Array, r_rec: Array, bias: Array,
+                   s0: Array) -> Tuple[Array, Array]:
+    """sLSTM over a sequence, one ``lax.scan`` step per time step.
+
+    xin [b, S, 4d] pre-activations of the input projection; r_rec
+    [nh, hd, 4hd] block-diagonal recurrent weights; bias [4d]; s0 [b, 4d]
+    the carry (h, c, n, m) side by side. Returns (h [b, S, d] f32, final
+    carry [b, 4d])."""
+    nh, hd = r_rec.shape[0], r_rec.shape[1]
+    d = nh * hd
+
+    def step(carry, xt):
+        h, c, n, m = carry
+        rec = jnp.einsum("bnd,ndk->bnk", h.reshape(-1, nh, hd),
+                         r_rec).reshape(-1, 4 * d)
+        pre = xt.astype(jnp.float32) + rec + bias
+        zt, it, ft, ot = jnp.split(pre, 4, axis=-1)
+        zt = jnp.tanh(zt)
+        ot = jax.nn.sigmoid(ot)
+        log_f = jax.nn.log_sigmoid(ft)
+        m_new = jnp.maximum(log_f + m, it)
+        i_p = jnp.exp(it - m_new)
+        f_p = jnp.exp(log_f + m - m_new)
+        c_new = f_p * c + i_p * zt
+        n_new = f_p * n + i_p
+        h_new = ot * c_new / jnp.maximum(n_new, 1e-6)
+        return (h_new, c_new, n_new, m_new), h_new
+
+    carry, hs = jax.lax.scan(step, tuple(jnp.split(s0, 4, axis=-1)),
+                             jnp.moveaxis(xin, 1, 0))
+    return jnp.moveaxis(hs, 0, 1), jnp.concatenate(carry, axis=-1)

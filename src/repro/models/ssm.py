@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kops
 from repro.models.layers import dense_init
 
 Array = jax.Array
@@ -181,7 +182,6 @@ def mamba_block(params, x: Array, cfg: ModelConfig,
     if h0 is None:
         h0 = jnp.zeros((b, nheads, n, headdim), jnp.float32)
     if cfg.attn_impl == "pallas":
-        from repro.kernels import ops as kops
         y, h_final = kops.ssd_scan(qq, kk, vv, log_decay, h0)
     else:
         y, h_final = chunked_linear_scan(qq, kk, vv, log_decay, h0)
@@ -273,7 +273,6 @@ def mlstm_block(params, x: Array, cfg: ModelConfig, h0: Array = None):
         if h0 is None:
             h0 = jnp.zeros((b, nh, hd, hd + 1), jnp.float32)
         if cfg.attn_impl == "pallas":
-            from repro.kernels import ops as kops
             y_aug, h_final = kops.ssd_scan(q, k, v_aug, f, h0)
         else:
             y_aug, h_final = chunked_linear_scan(q, k, v_aug, f, h0)
@@ -339,21 +338,20 @@ def slstm_cell(params, xt: Array, carry, cfg: ModelConfig):
 
 
 def slstm_block(params, x: Array, cfg: ModelConfig, carry=None):
-    """x: [b, S, d] -> [b, S, d]; sequential scan over time."""
+    """x: [b, S, d] -> [b, S, d]; the recurrence over time runs as one
+    fused, differentiable op (``kernels/slstm_scan.py``)."""
     with jax.named_scope("slstm"):
         b, s, d = x.shape
         xin = x @ params["w_in"]                             # [b, S, 4d]
         if carry is None:
             zeros = jnp.zeros((b, d), jnp.float32)
             carry = (zeros, zeros, zeros, jnp.full((b, d), -1e30, jnp.float32))
-
-        def step(carry, xt):
-            new = slstm_cell(params, xt, carry, cfg)
-            return new, new[0]
-
-        carry, hs = jax.lax.scan(step, carry, jnp.moveaxis(xin, 1, 0))
-        y = jnp.moveaxis(hs, 0, 1).astype(x.dtype)           # [b, S, d]
-        return y @ params["out_proj"], carry
+        with jax.named_scope("slstm_recurrence"):
+            hs, s_final = kops.slstm_scan(xin, params["r_rec"],
+                                          params["bias"],
+                                          jnp.concatenate(carry, axis=-1))
+        y = hs.astype(x.dtype)                               # [b, S, d]
+        return y @ params["out_proj"], tuple(jnp.split(s_final, 4, axis=-1))
 
 
 def slstm_decode_step(params, x: Array, cfg: ModelConfig, carry):
