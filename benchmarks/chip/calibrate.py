@@ -41,7 +41,10 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import harness  # noqa: E402
 
 
-def train_seed(cell, seed, modes, devices, log) -> list:
+def train_seed(cell, seed, modes, devices, log, built: dict) -> list:
+    """The rows of one seed. ``built`` keeps each fault's step across
+    seeds (``half_batch`` breaks only the feed, so it shares the sound
+    step), so that each is traced and compiled once a process."""
     import jax
     from repro.models.model import Model
     import compare
@@ -64,8 +67,11 @@ def train_seed(cell, seed, modes, devices, log) -> list:
                 ctx, ring, precision="fp8" if mode == "control" else mode)
         else:
             fault = None if mode == "program" else mode
-            step, state, _, got = fl.program_readings(ctx, model, grid,
-                                                      ring, fault)
+            key = None if fault == "half_batch" else fault
+            if key not in built:
+                built[key] = fl.build_step(ctx, model, grid, key)
+            step, state, _, got = fl.program_readings(
+                ctx, model, grid, ring, fault, built=built[key])
             del step, state
             gc.collect()
         nums = compare.train_numbers(got, ref)
@@ -91,8 +97,10 @@ def main(argv=None) -> int:
     harness.use_bench_cache()
     log = lambda msg: print(f"[calibrate] {msg}", file=sys.stderr,
                             flush=True)
+    built = {}
     for seed in (int(s) for s in args.seeds.split(",")):
-        rows = train_seed(cell, seed, args.modes.split(","), devices, log)
+        rows = train_seed(cell, seed, args.modes.split(","), devices, log,
+                          built)
         for r in rows:
             print(json.dumps(r), flush=True)
         gc.collect()

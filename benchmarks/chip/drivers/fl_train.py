@@ -1,21 +1,25 @@
-"""Driver for federated training cells on one chip: one FL iteration per
-call.
+"""Driver for federated training cells: one FL iteration per call, on the
+cell's devices.
 
-The timed path is the program's own train step,
-``launch/train.jit_train_step`` (state donated): every peer's local
-momentum-SGD steps, then the MAR aggregation of params and momentum.
-Token batches come from a seeded ring made in set-up and cross to the
-device each call, shaped [P, B, n_micro, mb, s] as ``train.py`` feeds
-them.
+The timed path is the program's own train step
+(``core/fl_device.make_fl_train_step``), jitted with its state donated
+as ``launch/train.jit_train_step`` does: every peer's local momentum-SGD
+steps, then the MAR aggregation of params and momentum. On n > 1 devices
+the peers lie on a (data=n, model=1) mesh of the cell's devices, state
+and batch laid out by ``runtime/sharding`` (``state_shardings``,
+``batch_shardings``), and the jit takes those shardings, so MAR's rounds
+cross between chips. Token batches come from a seeded ring made in
+set-up and cross to the device(s) each call, shaped
+[P, B, n_micro, mb, s] as ``train.py`` feeds them.
 
 Set-up builds the step and its state from the seed, and drives them
 through their first ``check_steps`` calls on the first ring entries;
 that same step and state then run the window. Once the window has
 closed and the state is freed, the plain reference follows those first
-calls from the same weights and batches, and ``compare.train_checks``
-holds the first call's gradient as the optimizer received it (momentum /
-(1 - mu)) and the change of the params over the calls to the cell's
-limits.
+calls from the same weights and batches on the cell's devices
+(``refmath.fl_readings``), and ``compare.train_checks`` holds the first
+call's gradient as the optimizer received it (momentum / (1 - mu)) and
+the change of the params over the calls to the cell's limits.
 
 ``fault`` (tests and calibration only) breaks the timed path:
   "frozen"     the step returns its state unchanged;
@@ -29,6 +33,7 @@ import collections
 import json
 import math
 import time
+from types import SimpleNamespace
 
 import compare
 import harness
@@ -60,19 +65,70 @@ def make_ring(tr: dict, vocab_size: int, seed: int, n: int) -> list:
                                      seed, n)
 
 
-def build_step(ctx, model, grid, fault=None):
-    """(jitted step, batch placer)."""
+def shardings(devices, model, tr: dict):
+    """None on one device. On n devices: the state's and the batch's
+    ``NamedSharding``s, and a replicated one, over a (data=n, model=1)
+    mesh of ``devices``: one peer per device, as ``runtime/sharding`` lays
+    a peer-stacked state out."""
+    if len(devices) == 1:
+        return None
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.runtime.sharding import (batch_shardings, make_shard_plan,
+                                        state_shardings)
+    mesh = Mesh(np.asarray(devices).reshape(len(devices), 1),
+                ("data", "model"))
+    plan = make_shard_plan(mesh)
+    if plan.n_peers != tr["peers"]:
+        raise harness.BenchError(f"{tr['peers']} peers on {plan.n_peers} "
+                                 f"devices: the mesh takes one per device")
+    cfg = model.cfg
+    state, batch = abstract_args(model, tr, None)
+    return SimpleNamespace(
+        state=state_shardings(state, plan, head_dim=cfg.head_dim,
+                              num_heads=cfg.num_heads,
+                              num_kv_heads=cfg.num_kv_heads),
+        batch=batch_shardings(batch, plan),
+        replicated=NamedSharding(mesh, PartitionSpec()))
+
+
+def abstract_args(model, tr: dict, sh) -> tuple:
+    """(state, batch) of a call as ``ShapeDtypeStruct``s, laid out by
+    ``sh`` (``shardings``, or None) as the window's arrays are, to lower
+    the step without making them: the sLSTM kernels find the mesh they
+    run on from their operands' shardings."""
     import jax
     import jax.numpy as jnp
+    from repro.core.fl_device import fl_state_shape
+    state = fl_state_shape(model, tr["peers"])
+    batch = {k: jax.ShapeDtypeStruct(batch_shape(tr), jnp.int32)
+             for k in ("tokens", "labels")}
+    if sh is None:
+        return state, batch
+    put = lambda x, s: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=s)
+    return jax.tree.map(put, state, sh.state), jax.tree.map(
+        put, batch, sh.batch)
+
+
+def build_step(ctx, model, grid, fault=None) -> tuple:
+    """(jitted step, batch placer, ``shardings``) on ``ctx.devices``."""
+    import jax
     from repro.core import fl_device
-    from repro.launch import train
 
     tr = ctx.traffic
     shape = batch_shape(tr)
+    sh = shardings(ctx.devices, model, tr)
+
+    def feed(raw):
+        # on one device, uncommitted to JAX's default one, as train.py's
+        return jax.device_put({k: v.reshape(shape) for k, v in raw.items()},
+                              None if sh is None else sh.batch)
     if fault is None or fault == "half_batch":
         from repro.core.aggregation import build_pipeline
-        pipeline = build_pipeline("mar", grid, backend="device")
-        step = train.jit_train_step(model, grid, tr["lr"], pipeline)
+        fn = fl_device.make_fl_train_step(
+            model, grid, lr=tr["lr"],
+            pipeline=build_pipeline("mar", grid, backend="device"))
     else:
         inner = fl_device.make_fl_train_step(
             model, grid, lr=tr["lr"], aggregate=fault != "no_mar")
@@ -80,10 +136,10 @@ def build_step(ctx, model, grid, fault=None):
         def frozen(state, batch):
             _, metrics = inner(state, batch)
             return state, metrics
-        step = jax.jit(frozen if fault == "frozen" else inner,
-                       donate_argnums=0)
-    return step, lambda raw: {
-        k: jnp.asarray(v.reshape(shape)) for k, v in raw.items()}
+        fn = frozen if fault == "frozen" else inner
+    kw = {} if sh is None else dict(in_shardings=(sh.state, sh.batch),
+                                    out_shardings=(sh.state, sh.replicated))
+    return jax.jit(fn, donate_argnums=0, **kw), feed, sh
 
 
 def _feed_rows(tr: dict, raw: dict, fault=None) -> dict:
@@ -98,17 +154,20 @@ def _feed_rows(tr: dict, raw: dict, fault=None) -> dict:
     return out
 
 
-def program_readings(ctx, model, grid, ring, fault=None) -> tuple:
-    """Set-up: build the step and state, drive the first calls. Returns
+def program_readings(ctx, model, grid, ring, fault=None,
+                     built=None) -> tuple:
+    """Set-up: build the step (or take ``built``, a ``build_step`` result
+    for the same fault) and the state, drive the first calls. Returns
     (step, state, feed, readings of those calls). The readings' first
     gradient is the momentum after the first call over (1 - mu); its
     leaves ("grad1_leaves", float32 numpy) are peer 0's: after MAR all
     peers hold the same."""
     import jax
     tr = ctx.traffic
-    step, feed = build_step(ctx, model, grid, fault)
+    step, feed, sh = built or build_step(ctx, model, grid, fault)
     shape = model.init_shape()
-    state = weights.make_fl_state(shape, tr["peers"], ctx.seed)
+    state = weights.make_fl_state(shape, tr["peers"], ctx.seed,
+                                  None if sh is None else sh.state)
     jax.block_until_ready(state)
     ctx.phase("state made")
     losses, grad1, leaves = [], None, None
@@ -123,7 +182,8 @@ def program_readings(ctx, model, grid, ring, fault=None) -> tuple:
                                          1.0 / (1.0 - tr["mu"]), peer=0)
     # the initial weights again, made from the seed once the step's
     # working memory is free
-    theta0 = weights.make_params(shape, ctx.seed)
+    theta0 = weights.make_params(shape, ctx.seed,
+                                 None if sh is None else sh.replicated)
     dtheta = refmath.leaf_norms(state["params"], peer_axis=True,
                                 minus=jax.tree.map(lambda x: x[None], theta0))
     del theta0
@@ -133,7 +193,8 @@ def program_readings(ctx, model, grid, ring, fault=None) -> tuple:
 
 def reference_readings(ctx, ring, precision="highest") -> dict:
     """The plain reference over the same first calls, from the same
-    weights, on the cell's chip; ``"head"`` names its output head's leaf."""
+    weights, on the cell's devices; ``"head"`` names its output head's
+    leaf."""
     import jax
     from repro.models.model import Model
     tr = ctx.traffic
@@ -142,11 +203,10 @@ def reference_readings(ctx, ring, precision="highest") -> dict:
     layout = batch_shape(tr)
     batches = [{k: v.reshape(layout) for k, v in ring[t].items()}
                for t in range(tr["check_steps"])]
-    with jax.default_device(ctx.devices[0]):
-        out = refmath.fl_readings(
-            ref.loss_fn(ctx.config["model"], precision),
-            lambda: weights.make_params(shape, ctx.seed), batches,
-            tr["grid"], tr["lr"], tr["mu"], tr["check_steps"])
+    out = refmath.fl_readings(
+        ref.loss_fn(ctx.config["model"], precision),
+        lambda: weights.make_params(shape, ctx.seed), batches,
+        tr["grid"], tr["lr"], tr["mu"], tr["check_steps"], ctx.devices)
     out["head"] = ref.HEAD
     return out
 

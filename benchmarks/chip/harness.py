@@ -10,6 +10,7 @@ found by the name ``BENCHMARK.json`` gives it:
   drivers/<driver>.py          ``run(ctx) -> dict``
   layer_metrics/<metric>.py    ``read(inp) -> float | None``
   references/<reference>.py    the plain float32 model the check runs
+  work/<work>.py               the configuration's operations per token
 
 This module imports no JAX at import time, so the tests can collect it
 without touching an accelerator.
@@ -258,5 +259,6 @@ class Ctx:
 def layer_inputs(ctx: Ctx, trace_obj, peak: dict) -> SimpleNamespace:
     """What a per-layer metric reader gets."""
     return SimpleNamespace(trace=trace_obj, counters=ctx.counters,
-                           peak=peak, model=ctx.config["model"],
-                           traffic=ctx.traffic, chips=ctx.chips)
+                           peak=peak, config=ctx.config,
+                           model=ctx.config["model"], traffic=ctx.traffic,
+                           chips=ctx.chips)

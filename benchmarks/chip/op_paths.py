@@ -142,20 +142,17 @@ def split(trace, paths: dict, rules) -> dict:
 
 def compiled_step(inp) -> str:
     """The text of the cell's FL step as its module under ``drivers/``
-    builds it, compiled for the window's shapes."""
+    builds it on the cell's ``inp.chips`` devices (the mesh program on
+    several), compiled for the window's shapes and layouts."""
     import jax
-    import jax.numpy as jnp
-    import weights
     from repro.models.model import Model
     tr = inp.traffic
     fl = harness.load_module("drivers", tr["driver"])
     model = Model(harness.model_config({"model": inp.model}))
-    step, _ = fl.build_step(SimpleNamespace(traffic=tr), model, fl._grid(tr))
-    state = jax.eval_shape(
-        lambda: weights.make_fl_state(model.init_shape(), tr["peers"], 0))
-    batch = {k: jax.ShapeDtypeStruct(fl.batch_shape(tr), jnp.int32)
-             for k in ("tokens", "labels")}
-    return step.lower(state, batch).compile().as_text()
+    devices = jax.devices()[:inp.chips]
+    step, _, sh = fl.build_step(
+        SimpleNamespace(traffic=tr, devices=devices), model, fl._grid(tr))
+    return step.lower(*fl.abstract_args(model, tr, sh)).compile().as_text()
 
 
 def splits(inp):

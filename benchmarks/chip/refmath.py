@@ -129,25 +129,39 @@ def host_leaves(tree, scale: float = 1.0, peer: int | None = None) -> dict:
 
 
 def fl_readings(loss_fn, make_theta0, batches, grid, lr: float, mu: float,
-                steps: int) -> dict:
+                steps: int, devices) -> dict:
     """The federated iteration, plainly: every peer takes its local
     momentum-SGD steps (g the mean of its micro-batches' gradients; m = mu
     m + (1 - mu) g; theta = theta - lr m, in float32, theta kept in each
     leaf's own dtype as the configuration states it, momentum in
-    float32), then MAR over ``grid`` (``mar_mean``) averages (theta, m).
+    float32), then MAR over ``grid`` (``mar_mean``, leaf by leaf)
+    averages (theta, m).
 
     ``make_theta0()`` makes the initial params on the default device
     (called twice: to start, and to measure the change at the end).
     ``batches[t]`` holds {"tokens", "labels"} [n_peers, local_steps,
-    n_micro, mb, seq] (numpy) for iteration ``t``. The peers run one after another on
-    the default device; all state stays there.
+    n_micro, mb, seq] (numpy) for iteration ``t``.
+
+    Placement: the shared start, MAR and the readings live on
+    ``devices[0]``; peer ``p`` computes on ``devices[p % len(devices)]``,
+    the peers one after another as the host sends them. While peers
+    outnumber the entries of ``devices``, a peer that has finished waits
+    in host memory, so a device holds the shared start and one running
+    peer at most. (A device may be listed more than once: all state then
+    stays on it.) Placement moves bits and changes no arithmetic.
 
     Returns {"loss": [per iteration, mean over peers and local steps],
     "grad1": {leaf: norm of m / (1 - mu) after the first iteration},
     "grad1_leaves": {leaf: m / (1 - mu) after the first iteration, as a
     float32 numpy array}, "dtheta": {leaf: norm of theta_steps - theta0}}.
     """
-    theta = make_theta0()
+    home = devices[0]
+    n_peers = int(np.prod(grid))
+    park = n_peers > len(devices)
+    with jax.default_device(home):
+        theta = make_theta0()
+        m = jax.jit(lambda t: jax.tree.map(
+            lambda x: jnp.zeros(x.shape, jnp.float32), t))(theta)
     dtypes = jax.tree.map(lambda x: x.dtype, theta)
 
     def local(theta, m, tokens, labels):
@@ -167,32 +181,46 @@ def fl_readings(loss_fn, make_theta0, batches, grid, lr: float, mu: float,
         return theta, m, loss
     local = jax.jit(local, donate_argnums=(1,))
     copy = jax.jit(lambda t: jax.tree.map(jnp.copy, t))
+    mar_leaf = jax.jit(mar_mean, static_argnums=(1, 2))
 
     def mar(peers):
-        thetas = [p[0] for p in peers]
-        ms = [p[1] for p in peers]
-        return (jax.tree.map(lambda dt, *xs: mar_mean(xs, grid, dt),
-                             dtypes, *thetas),
-                jax.tree.map(lambda *xs: mar_mean(xs, grid, jnp.float32),
-                             *ms))
-    mar = jax.jit(mar, donate_argnums=0)
+        """(theta, m) of MAR over the peers' [theta leaves, m leaves], one
+        leaf at a time on ``home``; each leaf's inputs are dropped once
+        its mean is made."""
+        out = []
+        for j, dts in enumerate((leaf_dtypes, [jnp.float32] * n_leaves)):
+            new = []
+            for i, dt in enumerate(dts):
+                xs = [jax.device_put(p[j][i], home) for p in peers]
+                for p in peers:
+                    p[j][i] = None
+                new.append(mar_leaf(xs, tuple(grid), dt))
+                del xs
+            out.append(jax.tree.unflatten(treedef, new))
+        return out
 
-    m = jax.jit(lambda t: jax.tree.map(
-        lambda x: jnp.zeros(x.shape, jnp.float32), t))(theta)
-    n_peers = int(np.prod(grid))
+    treedef = jax.tree.structure(theta)
+    leaf_dtypes = jax.tree.leaves(dtypes)
+    n_leaves = len(leaf_dtypes)
     out = {"loss": []}
     for t in range(steps):
         peers, losses = [], []
         for p in range(n_peers):
-            # the last peer takes the shared momentum itself, the others
-            # a copy; params are never written in place
-            mo = m if p == n_peers - 1 else copy(m)
-            th = theta
+            dev = devices[p % len(devices)]
+            last = p == n_peers - 1
+            if dev == home:
+                # the last peer takes the shared momentum itself, the
+                # others a copy; params are never written in place
+                th, mo = theta, (m if last else copy(m))
+            else:
+                th, mo = jax.device_put((theta, m), dev)
             for b in range(batches[t]["tokens"].shape[1]):
                 th, mo, loss = local(th, mo, batches[t]["tokens"][p, b],
                                      batches[t]["labels"][p, b])
                 losses.append(loss)
-            peers.append((th, mo))
+            if park and not last:
+                th, mo = jax.device_get((th, mo))
+            peers.append([jax.tree.leaves(th), jax.tree.leaves(mo)])
             del th, mo
         del theta, m
         theta, m = mar(peers)
@@ -203,5 +231,6 @@ def fl_readings(loss_fn, make_theta0, batches, grid, lr: float, mu: float,
                             for k, v in leaf_norms(m).items()}
             out["grad1_leaves"] = host_leaves(m, 1.0 / (1.0 - mu))
     del m
-    out["dtheta"] = leaf_norms(theta, minus=make_theta0())
+    with jax.default_device(home):
+        out["dtheta"] = leaf_norms(theta, minus=make_theta0())
     return out

@@ -65,3 +65,13 @@ def test_peaks_table():
     assert peak["hbm_bytes_per_s"] == 819e9
     with pytest.raises(harness.BenchError):
         harness.load_peaks("TPU v99")
+
+
+def test_metric_workloads_name_cells():
+    cells = {w["name"]: w for w in SPEC["workloads"]}
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        for c in m.get("workloads", []):
+            assert c in cells, (m["name"], c)
+        # collectives exist only across chips: one chip reads nothing
+        if m["name"].startswith("mar_"):
+            assert all(cells[c]["chips"] > 1 for c in m["workloads"])

@@ -87,7 +87,8 @@ def test_classes_match_whole_scope_names(path, phase, block):
 def _tiny_inp():
     c = tiny.cell(CELL)
     assert c.config["model"]["remat"] == "block"
-    return SimpleNamespace(traffic=c.traffic, model=c.config["model"])
+    return SimpleNamespace(traffic=c.traffic, model=c.config["model"],
+                           chips=c.chips)
 
 
 @pytest.fixture(scope="module")

@@ -79,3 +79,63 @@ def test_load_a_recorded_trace(tmp_path):
     assert t.window_s > 0
     w0, w1 = t.window
     assert all(w0 <= a <= b <= w1 for a, b in t.spans["bench.step"])
+
+
+def four_device_trace():
+    # window 0..100 ms, four devices running one step each. Devices 0
+    # and 2: compute 0-40, a blocking all-reduce 40-60 (exposed), an
+    # async all-reduce 10-30 under the compute (hidden). Device 1:
+    # compute 0-50, all-reduce 50-60. Device 3: as device 0 without the
+    # async one, then a fusion that reads the all-reduce's result, 60-70:
+    # its text names the all-reduce, yet it computes.
+    def dev(compute_end, ar, extra=()):
+        return [("%fusion.1 = f32[4]{0} fusion(%p)", 0, compute_end * MS,
+                 "jit(step)/fl.grad/dot"),
+                ("%all-reduce.1 = f32[4]{0} all-reduce(%fusion.1)",
+                 ar[0] * MS, ar[1] * MS, "jit(step)/fl.aggregate/psum"),
+                *extra]
+    reads = ("%fusion.9 = f32[4]{0} fusion(%all-reduce.1), kind=kLoop",
+             60 * MS, 70 * MS, "jit(step)/fl.aggregate/convert")
+    ops = {0: dev(40, (40, 60)), 1: dev(50, (50, 60)), 2: dev(40, (40, 60)),
+           3: dev(40, (40, 60), (reads,))}
+    async_ops = {d: [("%all-reduce-start.2 = f32[4]{0} all-reduce-start("
+                      "%p)", 10 * MS, 30 * MS)] for d in (0, 2)}
+    return trace_reduce.Trace(ops, {"bench.window": [(0, 100 * MS)]},
+                              (0, 100 * MS), async_ops)
+
+
+def _collective_readers(trace, calls=2):
+    import harness
+    from types import SimpleNamespace
+    inp = SimpleNamespace(trace=trace, counters={"traced_calls": calls})
+    return {m: harness.load_module("layer_metrics", m).read(inp)
+            for m in ("mar_collective_ms.train", "mar_exposed_share.train")}
+
+
+def test_collective_readers_on_four_devices():
+    t = four_device_trace()
+    coll, exposed = t.collective_exposed()
+    # collective ms by device 40, 10, 40, 20; exposed 20, 10, 20, 20
+    assert coll == pytest.approx(0.0275)
+    assert exposed == pytest.approx(0.0175)
+    got = _collective_readers(t)
+    assert got["mar_collective_ms.train"] == pytest.approx(13.75)
+    assert got["mar_exposed_share.train"] == pytest.approx(100 * 17.5 / 27.5)
+
+
+def test_an_op_that_reads_a_collective_is_not_one():
+    assert trace_reduce.is_collective(
+        "%all-reduce-start.2 = f32[4]{0} all-reduce-start(%p)")
+    assert trace_reduce.is_collective("all-gather.7")
+    assert not trace_reduce.is_collective(
+        "%fusion.9 = f32[4]{0} fusion(%all-reduce.1), kind=kLoop")
+
+
+def test_collective_readers_read_nothing_without_a_collective():
+    one = trace_reduce.Trace(
+        {0: [("%fusion.1 = f32[4]{0} fusion(%p)", 0, 50 * MS, "dot")]},
+        {"bench.window": [(0, 100 * MS)]}, (0, 100 * MS))
+    assert _collective_readers(one) == {"mar_collective_ms.train": None,
+                                        "mar_exposed_share.train": None}
+    assert _collective_readers(four_device_trace(), calls=0) == {
+        "mar_collective_ms.train": None, "mar_exposed_share.train": None}

@@ -1,13 +1,15 @@
-"""work.py's counts against hand counts, and against the program's own
-parameter leaves."""
+"""The xLSTM work model's counts (``work/xlstm.py``, the one xlstm-350m
+names) against hand counts, and against the program's own parameter
+leaves."""
 import numpy as np
 import pytest
 
 import tiny
 import harness
-import work
 
-XL = harness.load_json("configs", "xlstm-350m")["model"]
+CFG = harness.load_json("configs", "xlstm-350m")
+XL = CFG["model"]
+work = harness.load_module("work", CFG["work"])
 
 
 def test_xlstm_params_by_hand():
@@ -37,3 +39,20 @@ def test_xlstm_train_flops_by_hand():
     slstm = 2 * (d * 4 * d + 4 * 256 * 1024 + d * d)
     fwd = 21 * mlstm + 3 * slstm + 2 * d * V
     assert work.train_flops_per_token(XL, 2048) == pytest.approx(3 * fwd)
+
+
+def test_train_mfu_reads_the_work_model_the_config_names():
+    from types import SimpleNamespace
+    from test_bench_trace import hand_trace
+    peak = harness.load_peaks("TPU v5 lite")
+    inp = SimpleNamespace(trace=hand_trace(), config=CFG, model=XL,
+                          traffic={"seq": 2048}, chips=1, peak=peak,
+                          counters={"traced_calls": 2,
+                                    "tokens_per_call": 4096})
+    read = harness.load_module("layer_metrics", "train_mfu").read
+    want = 100.0 * work.train_flops_per_token(XL, 2048) * 2 * 4096 / (
+        0.1 * peak["bf16_flops_per_s"])
+    assert read(inp) == pytest.approx(want)
+    with pytest.raises(harness.BenchError):
+        read(SimpleNamespace(**{**vars(inp),
+                                "config": {**CFG, "work": "no-such"}}))

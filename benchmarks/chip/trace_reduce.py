@@ -24,6 +24,15 @@ OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
+
+
+def is_collective(event_name: str) -> bool:
+    """A collective by its instruction's name (``%all-reduce.3 = ...``),
+    not by the operands of the text after it: a fusion that reads an
+    all-reduce's result names it there."""
+    return bool(COLLECTIVE.search(event_name.split(" = ")[0]))
+
+
 KEY_CHARS = 160
 
 
@@ -117,12 +126,12 @@ class Trace:
         tot = exposed = 0.0
         for d, evs in self.ops.items():
             spans = [(a, b) for n, a, b, k, s, leaf in evs
-                     if leaf and COLLECTIVE.search(n)]
+                     if leaf and is_collective(n)]
             spans += [(a, b) for n, a, b in self.async_ops.get(d, [])
-                      if COLLECTIVE.search(n)]
+                      if is_collective(n)]
             coll = _union(spans)
             comp = _union([(a, b) for n, a, b, k, s, leaf in evs
-                           if leaf and not COLLECTIVE.search(n)])
+                           if leaf and not is_collective(n)])
             tot += _length(coll)
             exposed += _length(coll) - _length(_intersect(coll, comp))
         n = max(len(self.ops), 1)
@@ -202,7 +211,7 @@ def load(path: str) -> Trace:
                 elif line.name == ASYNC_LINE:
                     raw_async[int(m.group(1))] = [
                         (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                        for e in line.events if COLLECTIVE.search(e.name)]
+                        for e in line.events if is_collective(e.name)]
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:

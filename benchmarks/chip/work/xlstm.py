@@ -1,4 +1,6 @@
-"""Operations and bytes from shapes, for the models as the program runs them.
+"""Operations and parameters from shapes, for the xLSTM stack as the program
+runs it: the work model a configuration names under ``"work"`` (one file
+per model family under ``work/``, found by that name).
 
 Each function takes a config file's ``model`` dict. Counts are of
 multiply-adds as two operations, for what the program computes: the
